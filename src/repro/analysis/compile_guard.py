@@ -63,6 +63,10 @@ class _CompileLogHandler(logging.Handler):
         if not m:
             return
         name = m.group(1)
+        # JAX 0.9 logs "Compiling jit(name) ..."; budgets key on the bare name
+        wrapped = re.fullmatch(r"jit\((.*)\)", name)
+        if wrapped:
+            name = wrapped.group(1)
         for g in list(self.guards):
             g._record(name)
 

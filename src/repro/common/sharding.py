@@ -86,12 +86,8 @@ def shard_tree(tree_axes, mesh: Mesh, rules=None):
 
 
 def mesh_context(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` where available; older jax (< 0.5) falls back
-    to the ``Mesh`` context manager. Use for every ``with <mesh>:`` block so
-    lowering code runs across jax versions."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh(mesh)``: use for every ``with <mesh>:`` block."""
+    return jax.set_mesh(mesh)
 
 
 def group_sharding(shape, mesh: Mesh, rules=None) -> NamedSharding:

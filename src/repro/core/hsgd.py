@@ -837,7 +837,15 @@ class HSGDRunner:
 
             return jax.lax.scan(body, state, None, length=rounds)
 
-        state, losses = go(state, data, group_weights)
+        if mesh is None or mesh.devices.size <= 1:
+            state, losses = go(state, data, group_weights)
+        else:
+            # trace under the mesh: the federation's group-axis constraints
+            # and the compress kernel's shard_map read it
+            from repro.common.sharding import mesh_context
+
+            with mesh_context(mesh):
+                state, losses = go(state, data, group_weights)
         return state, losses.reshape(-1)
 
     def run_private(self, state: HSGDState, data, group_weights, rounds: int,

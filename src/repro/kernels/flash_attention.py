@@ -14,8 +14,7 @@ so the per-layer window array a `lax.scan` threads through the stacked
 layers (a traced scalar) never forces a recompile per window value.
 
 Backend selection: ``interpret=None`` auto-detects — compiled Mosaic on TPU,
-interpret mode elsewhere (``REPRO_PALLAS_COMPILED`` overrides), the same
-policy as the fused compression kernel.
+interpret mode elsewhere, the same policy as the fused compression kernel.
 """
 from __future__ import annotations
 
@@ -33,7 +32,8 @@ NEG_INF = -2.0e38
 
 
 def _flash_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                  scale: float, block_q: int, block_k: int, seq_len: int):
+                  scale: float, block_q: int, block_k: int, seq_len: int,
+                  padded_len: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -51,9 +51,14 @@ def _flash_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [bq, bk]
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    ok = (k_pos <= q_pos) & (k_pos < seq_len) & (q_pos < seq_len)
-    window = win_ref[0, 0]  # runtime scalar; <=0 means full causal
-    ok &= jnp.where(window > 0, k_pos > (q_pos - window), True)
+    # window <= 0 means full causal. Resolve it to a span on the scalar unit:
+    # a span longer than the padded sequence admits every causal key. Mosaic
+    # cannot lower a select whose operands are i1 vectors, so the mask is
+    # built from int32 compares and `&` only.
+    window = win_ref[0, 0]
+    span = jnp.where(window > 0, window, padded_len + 1)
+    ok = ((k_pos <= q_pos) & (k_pos > q_pos - span)
+          & (k_pos < seq_len) & (q_pos < seq_len))
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[...]  # [bq, 1]
@@ -101,7 +106,7 @@ def flash_attention_pallas(
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            seq_len=S,
+            seq_len=S, padded_len=Sp,
         ),
         grid=grid,
         in_specs=[

@@ -2,9 +2,9 @@
 
 Backend selection is automatic: compiled Mosaic kernels on TPU, interpret
 mode elsewhere (interpret executes the same kernel body for validation).
-``REPRO_PALLAS_COMPILED=1/0`` forces the choice. The fused compression op
-additionally short-circuits to its bit-identical jnp reference off-TPU —
-interpret-mode Pallas is for validation, not the hot path.
+The fused compression op additionally short-circuits to its bit-identical
+jnp reference off-TPU — interpret-mode Pallas is for validation, not the hot
+path.
 """
 from __future__ import annotations
 

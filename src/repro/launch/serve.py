@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.backend import enable_compile_cache
 from repro.common.config import get_config
 from repro.launch.engine import (CACHE_DTYPES, ServeEngine, parse_cache_dtype,
                                  sequential_decode, sequential_prefill,
@@ -41,6 +42,7 @@ def build_inputs(cfg, batch: int, prompt_len: int, seed: int = 0):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true", default=True)

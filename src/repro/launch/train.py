@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import save_checkpoint
+from repro.common.backend import enable_compile_cache
 from repro.common.config import FederationConfig, TrainConfig, get_config
 from repro.core import metrics as MET
 from repro.core.baselines import make_runner, merge_groups_for_tdcd
@@ -53,7 +54,10 @@ def make_paper_model(name: str, dataset: str):
     return lstm_hybrid(n_features=76, hospital_features=36, n_classes=spec.n_classes)
 
 
-def run_ehealth(args) -> dict:
+def ehealth_setup(args):
+    """(spec, fed, train, model, X, y, data) of an e-health run: the
+    federation, the paper model and the 3-tier partitioned data, placed on
+    the default device. ``data`` is the stacked [M, ...] federated set."""
     spec = DATASETS[args.dataset]
     fed = FederationConfig(
         num_groups=args.groups,
@@ -78,6 +82,12 @@ def run_ehealth(args) -> dict:
     if algo in ("tdcd", "c-tdcd"):
         raw = merge_groups_for_tdcd(raw)
     data = {k: jnp.asarray(v) for k, v in raw.items()}
+    return spec, fed, train, model, X, y, data
+
+
+def run_ehealth(args) -> dict:
+    spec, fed, train, model, X, y, data = ehealth_setup(args)
+    algo = args.algorithm
     w = make_group_weights(data)
 
     dp = args.dp_clip > 0.0 and args.dp_sigma > 0.0
@@ -150,6 +160,7 @@ def run_ehealth(args) -> dict:
     m = MET.evaluate_global(
         model, gm, flatten_for_tower(spec, X1), flatten_for_tower(spec, X2), y
     )
+    m["train_loss_first"] = float(losses[0]) if len(losses) else float("nan")
     m["train_loss_final"] = float(losses[-1]) if len(losses) else float("nan")
     m["steps"] = int(len(losses))
     m["wall_s"] = round(dt, 2)
@@ -404,7 +415,9 @@ def _validate_args(ap, args):
         ap.error("the privacy flags drive the e-health HSGD path, not --arch")
 
 
-def main(argv=None):
+def parse_args(argv=None):
+    """The CLI's validated arguments (``--model`` defaults to paper-cnn
+    unless ``--arch`` selects the LLM path)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default=None, choices=["paper-cnn", "paper-lstm"])
     ap.add_argument("--arch", default=None)
@@ -524,10 +537,16 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     _validate_args(ap, args)
+    if not args.arch and not args.model:
+        args.model = "paper-cnn"
+    return args
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
     if args.arch:
         return run_llm(args)
-    if not args.model:
-        args.model = "paper-cnn"
     return run_ehealth(args)
 
 

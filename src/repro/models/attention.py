@@ -262,7 +262,10 @@ def gqa_forward(
             # tail is all masked-out sentinels whose softmax terms are exact
             # zeros, so skipping it is bit-identical AND O(Sq²) not
             # O(Sq · cache_len). Long blocks go flash/online-softmax.
-            if Sq > BLOCKWISE_THRESHOLD:
+            # >=, not >: the engine prefills in power-of-two blocks, so a
+            # 2048-token block is the longest that prompts of 2049-4095
+            # tokens produce — with > they would never reach the flash path
+            if Sq >= BLOCKWISE_THRESHOLD:
                 out = _long_prefill_attention(q, k, v, positions, scale, window)
             else:
                 bias = causal_mask_bias(positions, positions, window)

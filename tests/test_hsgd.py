@@ -18,6 +18,7 @@ from repro.core.hsgd import (
 )
 from repro.data.partition import hybrid_partition
 from repro.data.synthetic import ORGANAMNIST, make_dataset
+from repro.launch.mesh import make_mesh
 from repro.models.split_model import cnn_hybrid
 
 
@@ -137,7 +138,7 @@ def test_run_with_trivial_mesh_matches_no_mesh():
     w = make_group_weights(data)
     s1 = init_state(jax.random.PRNGKey(0), model, fed, data)
     s2 = init_state(jax.random.PRNGKey(0), model, fed, data)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     _, l_plain = runner.run(s1, data, w, rounds=2)
     _, l_mesh = runner.run(s2, data, w, rounds=2, mesh=mesh)
     np.testing.assert_allclose(np.asarray(l_plain), np.asarray(l_mesh), rtol=1e-6)
@@ -146,7 +147,7 @@ def test_run_with_trivial_mesh_matches_no_mesh():
 def test_state_shardings_group_axis_and_replicated_scalars():
     model, fed, data = _mini()
     state = init_state(jax.random.PRNGKey(0), model, fed, data)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = state_shardings(state, mesh)
     theta0_spec = jax.tree_util.tree_leaves(sh.theta0)[0].spec
     assert theta0_spec and theta0_spec[0] in ("data", ("data",))  # M rides "data"
@@ -174,9 +175,10 @@ import jax, numpy as np
 from tests.test_hsgd import _mini
 from repro.common.config import TrainConfig
 from repro.core.hsgd import HSGDRunner, init_state, make_group_weights
+from repro.launch.mesh import make_mesh
 model, fed, data = _mini(M=4)  # M=4 divides both mesh sizes -> genuinely sharded
 w = make_group_weights(data)
-mesh = jax.make_mesh((%(n_dev)d, 1), ("data", "model"))
+mesh = make_mesh((%(n_dev)d, 1), ("data", "model"))
 out = {}
 for compression in (False, True):
     for do_agg in (False, True):
@@ -194,6 +196,23 @@ for compression in (False, True):
             "mesh": np.asarray(l_mesh).tolist(),
             "n_shards": len(leaf.sharding.device_set),
         }
+# the compress kernel's row-sharded path (shard_map over every mesh axis),
+# in interpret mode, against the jitted reference; 37 rows pad to the mesh
+from repro.core.compression import compress_rows_ref
+from repro.kernels.compress import _compress_rows_sharded
+ref = jax.jit(compress_rows_ref, static_argnames="levels")
+x = jax.random.normal(jax.random.PRNGKey(1), (37, 96))
+noise = jax.random.normal(jax.random.PRNGKey(2), (37, 96))
+clip, sigma = np.float32(1.0), np.float32(0.5)
+got = _compress_rows_sharded(mesh, x, 24, 128, None, None, None, None,
+                             interpret=True)
+got_dp = _compress_rows_sharded(mesh, x, 24, 128, None, clip, sigma, noise,
+                                interpret=True)
+out["sharded_compress"] = {
+    "plain": bool(np.array_equal(got, ref(x, 24, levels=128))),
+    "dp": bool(np.array_equal(got_dp, ref(x, 24, levels=128, dp_clip=clip,
+                                          dp_sigma=sigma, dp_noise=noise))),
+}
 print("RESULT::" + json.dumps(out))
 """
 
@@ -231,6 +250,15 @@ def test_group_sharded_run_matrix(n_dev, compression, do_global_agg):
     assert entry["n_shards"] == n_dev  # genuinely sharded, not replicated
     np.testing.assert_allclose(np.asarray(entry["plain"]),
                                np.asarray(entry["mesh"]), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_row_sharded_compress_matches_reference(n_dev):
+    """Under a mesh the compress kernel runs per device on a block of rows
+    (Mosaic kernels cannot be auto-partitioned); rows are independent, so
+    the result must equal the unsharded reference bit for bit."""
+    entry = _sharded_matrix(n_dev)["sharded_compress"]
+    assert entry == {"plain": True, "dp": True}
 
 
 def test_sampled_participants_valid_and_distinct():

@@ -15,6 +15,7 @@ from repro.common.config import INPUT_SHAPES, get_config, list_configs
 from repro.common.sharding import DEFAULT_RULES, divisible_spec, logical_to_spec
 from repro.core.comm_model import ICI, WAN, MessageSizes, round_time, total_comm_cost
 from repro.common.config import FederationConfig
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -94,7 +95,7 @@ def test_logical_to_spec_dedupes_axes():
 
 
 def test_divisible_spec_drops_non_divisible():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     from jax.sharding import PartitionSpec as P
 
     spec = divisible_spec((7, 16), P("model", "model"), mesh)
