@@ -179,9 +179,10 @@ def compress_message(x: jnp.ndarray, k_frac: float, levels: int = 0) -> jnp.ndar
 
 
 def compress_message_sort(x: jnp.ndarray, k_frac: float, levels: int = 0) -> jnp.ndarray:
-    """Pre-fusion reference path: sort-based top-k, then separate quantize.
+    """Exact top-k by sorting, then a separate quantize.
 
-    Kept only as the baseline for ``benchmarks/bench_hsgd_hotpath.py``.
+    The witness for exact top-k (the fused threshold search keeps >= k
+    entries a row) and the path of ``exchange(fused=False)``.
     """
     y = topk_sparsify_sort(x, k_frac) if 0.0 < k_frac < 1.0 else x
     if levels and levels > 1:

@@ -34,6 +34,21 @@ from repro.core.compression import compress_message_sort
 from repro.models.split_model import HybridModel
 from repro.optim import halving_schedule
 
+# Algorithm 1's phases as named scopes of the compiled round. Every op a phase
+# runs, its backward pass and XLA's fusions included, carries the scope's path
+# in its HLO metadata (op_name), and so in a device trace, where the benchmark
+# maps device time back to the phases. Scopes change metadata only.
+PHASE_SCOPES = (
+    "local_step/hospital",         # eqs. (5)-(6): the θ0/θ1 step
+    "local_step/device",           # eq. (7): the per-device θ2 step
+    "exchange",                    # lines 10-21: key split, fault and screen legs
+    "exchange/local_aggregation",  # eq. (1) and the line-15 broadcast
+    "exchange/sample",             # A_m/ξ_m draw and batch gather (line 13)
+    "exchange/intermediate",       # ζ1 = h1, ζ2 = h2
+    "exchange/compress",           # C-HSGD top-k + quantize (and DP)
+    "global_aggregation",          # eq. (2) and broadcasts, lines 3-9
+)
+
 
 class HSGDState(NamedTuple):
     theta0: Any  # [M, ...] combined models
@@ -148,9 +163,10 @@ def _local_grads(model: HybridModel, state: HSGDState):
     def h_loss(t0_m, t1_m, b_m, z2_m):
         return _hospital_loss(model, t0_m, t1_m, b_m, z2_m)
 
-    h_grads = jax.vmap(jax.value_and_grad(h_loss, argnums=(0, 1)))(
-        state.theta0, state.theta1, state.batch, state.stale["z2"]
-    )
+    with jax.named_scope("local_step/hospital"):
+        h_grads = jax.vmap(jax.value_and_grad(h_loss, argnums=(0, 1)))(
+            state.theta0, state.theta1, state.batch, state.stale["z2"]
+        )
     (losses, (g0, g1)) = h_grads
 
     def d_loss(t2_n, x2_n, y_n, t0_m, z1_n):
@@ -159,20 +175,23 @@ def _local_grads(model: HybridModel, state: HSGDState):
     per_device = jax.vmap(  # over devices within a group
         jax.grad(d_loss), in_axes=(0, 0, 0, None, 0)
     )
-    g2 = jax.vmap(per_device)(  # over groups
-        state.theta2, state.batch["x2"], state.batch["y"], state.stale["theta0"], state.stale["z1"]
-    )
+    with jax.named_scope("local_step/device"):
+        g2 = jax.vmap(per_device)(  # over groups
+            state.theta2, state.batch["x2"], state.batch["y"], state.stale["theta0"],
+            state.stale["z1"]
+        )
     return losses, g0, g1, g2
 
 
 def _apply_sgd(state: HSGDState, lr, g0, g1, g2) -> HSGDState:
     upd = lambda p, g: p - lr * g.astype(p.dtype)
-    return state._replace(
-        theta0=jax.tree.map(upd, state.theta0, g0),
-        theta1=jax.tree.map(upd, state.theta1, g1),
-        theta2=jax.tree.map(upd, state.theta2, g2),
-        step=state.step + 1,
-    )
+    with jax.named_scope("local_step/hospital"):
+        theta0 = jax.tree.map(upd, state.theta0, g0)
+        theta1 = jax.tree.map(upd, state.theta1, g1)
+    with jax.named_scope("local_step/device"):
+        theta2 = jax.tree.map(upd, state.theta2, g2)
+    return state._replace(theta0=theta0, theta1=theta1, theta2=theta2,
+                          step=state.step + 1)
 
 
 def local_sgd_step(model: HybridModel, state: HSGDState, lr) -> Tuple[HSGDState, jnp.ndarray]:
@@ -350,8 +369,8 @@ def exchange(
 
     With compression on, the whole exchange message (θ0 snapshot pytree + ζ1
     + ζ2) is compressed in ONE fused top-k+quantize row-matrix call (Pallas
-    kernel on TPU, fused jnp elsewhere). ``fused=False`` keeps the pre-fusion
-    leaf-wise sort-based path for benchmarking.
+    kernel on TPU, fused jnp elsewhere). ``fused=False`` takes the leaf-wise
+    exact top-k by sorting instead.
 
     The cohort path (see ``core/population.py``) pins the round's participants
     by passing ``idx`` ([M, A] data-row indices, padded to the bucket size by
@@ -373,72 +392,77 @@ def exchange(
     ``F.secure_agg_masks``) routes eq. (1) through the pairwise-mask secure-
     aggregation ring, where the masks cancel exactly in the server sum.
     """
-    dp = dp_clip is not None
-    if dp:  # extra split only on the DP trace: the plain key stream is untouched
-        key, k_sample, k_dp = jax.random.split(state.key, 3)
-    else:
-        key, k_sample = jax.random.split(state.key)
-        k_dp = None
-    if trust is not None and pmask is not None:
-        theta2_group = F.robust_local_aggregate(  # eq (1) under screening
-            state.theta2, pmask, trust,
-            method=fed.robust_agg, trim_frac=fed.trim_frac,
-            agg_masks=agg_masks)
-    elif agg_masks is not None:
-        theta2_group = F.secure_local_aggregate(  # eq (1) over masked uplinks
-            F.secure_mask_uplink(state.theta2, agg_masks), state.theta2, pmask)
-    else:
-        theta2_group = F.local_aggregate(state.theta2, pmask)  # eq (1)
-    A = fed.sampled_devices if idx is None else idx.shape[1]
-    theta2 = F.broadcast_to_devices(theta2_group, A)  # line 15
-
-    if idx is None:
-        idx = F.sample_participants(k_sample, fed)  # line 13
-    batch = F.gather_batch(data, idx)
-
-    z1 = _h1_groups(model, state.theta1, batch["x1"])
-    z2 = _h2_groups(model, theta2_group, batch["x2"])
-    stale_theta0 = state.theta0
-
-    if compression_k or quant_levels or dp:
-        msg = {"theta0": stale_theta0, "z1": z1, "z2": z2}
-        if fused:
-            from repro.kernels.compress import compress_pytree
-
-            msg = compress_pytree(msg, compression_k or 1.0, quant_levels,
-                                  dp_clip=dp_clip, dp_sigma=dp_sigma,
-                                  dp_key=k_dp)
+    with jax.named_scope("exchange"):
+        dp = dp_clip is not None
+        if dp:  # extra split only on the DP trace: the plain key stream is untouched
+            key, k_sample, k_dp = jax.random.split(state.key, 3)
         else:
-            if dp:
-                raise ValueError(
-                    "DP is fused into the batched compression kernel; "
-                    "the legacy sort path does not support dp_clip/dp_sigma")
-            comp = partial(compress_message_sort, k_frac=compression_k or 1.0,
-                           levels=quant_levels)
-            msg = jax.tree.map(comp, msg)
-        stale_theta0, z1, z2 = msg["theta0"], msg["z1"], msg["z2"]
+            key, k_sample = jax.random.split(state.key)
+            k_dp = None
+        with jax.named_scope("local_aggregation"):
+            if trust is not None and pmask is not None:
+                theta2_group = F.robust_local_aggregate(  # eq (1) under screening
+                    state.theta2, pmask, trust,
+                    method=fed.robust_agg, trim_frac=fed.trim_frac,
+                    agg_masks=agg_masks)
+            elif agg_masks is not None:
+                theta2_group = F.secure_local_aggregate(  # eq (1) over masked uplinks
+                    F.secure_mask_uplink(state.theta2, agg_masks), state.theta2, pmask)
+            else:
+                theta2_group = F.local_aggregate(state.theta2, pmask)  # eq (1)
+            A = fed.sampled_devices if idx is None else idx.shape[1]
+            theta2 = F.broadcast_to_devices(theta2_group, A)  # line 15
 
-    if msg_fault is not None:  # corruption hits the compressed uplink payload
-        def corrupt(z2):
-            def leaf(x):
-                f = msg_fault.reshape(
-                    (-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
-                return jnp.where(f != 0, x * f, x)
+        with jax.named_scope("sample"):
+            if idx is None:
+                idx = F.sample_participants(k_sample, fed)  # line 13
+            batch = F.gather_batch(data, idx)
 
-            return jax.tree.map(leaf, z2)
+        with jax.named_scope("intermediate"):
+            z1 = _h1_groups(model, state.theta1, batch["x1"])
+            z2 = _h2_groups(model, theta2_group, batch["x2"])
+        stale_theta0 = state.theta0
 
-        # cond, not where: clean rounds skip the corruption kernels entirely
-        z2 = jax.lax.cond(jnp.any(msg_fault != 0), corrupt, lambda z: z, z2)
-    if screen:  # receiver-side screen: drop (zero) non-finite ζ2 entries.
-        # Only the device uplink leg needs it: the fault model corrupts ζ2 in
-        # flight, while θ0/ζ1 originate from hospital state that the per-step
-        # group screen keeps finite — sweeping those (much larger) trees too
-        # costs real step time for no detection.
-        clean = lambda x: jnp.where(jnp.isfinite(x), x, jnp.zeros((), x.dtype))
-        z2 = jax.tree.map(clean, z2)
+        if compression_k or quant_levels or dp:
+            with jax.named_scope("compress"):
+                msg = {"theta0": stale_theta0, "z1": z1, "z2": z2}
+                if fused:
+                    from repro.kernels.compress import compress_pytree
 
-    stale = {"theta0": stale_theta0, "z1": z1, "z2": z2}
-    return state._replace(theta2=theta2, stale=stale, batch=batch, key=key)
+                    msg = compress_pytree(msg, compression_k or 1.0, quant_levels,
+                                          dp_clip=dp_clip, dp_sigma=dp_sigma,
+                                          dp_key=k_dp)
+                else:
+                    if dp:
+                        raise ValueError(
+                            "DP is fused into the batched compression kernel; "
+                            "the legacy sort path does not support dp_clip/dp_sigma")
+                    comp = partial(compress_message_sort, k_frac=compression_k or 1.0,
+                                   levels=quant_levels)
+                    msg = jax.tree.map(comp, msg)
+                stale_theta0, z1, z2 = msg["theta0"], msg["z1"], msg["z2"]
+
+        if msg_fault is not None:  # corruption hits the compressed uplink payload
+            def corrupt(z2):
+                def leaf(x):
+                    f = msg_fault.reshape(
+                        (-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
+                    return jnp.where(f != 0, x * f, x)
+
+                return jax.tree.map(leaf, z2)
+
+            # cond, not where: clean rounds skip the corruption kernels entirely
+            z2 = jax.lax.cond(jnp.any(msg_fault != 0), corrupt, lambda z: z, z2)
+        if screen:  # receiver-side screen: drop (zero) non-finite ζ2 entries.
+            # Only the device uplink leg needs it: the fault model corrupts ζ2 in
+            # flight, while θ0/ζ1 originate from hospital state that the per-step
+            # group screen keeps finite — sweeping those (much larger) trees too
+            # costs real step time for no detection.
+            clean = lambda x: jnp.where(jnp.isfinite(x), x, jnp.zeros((), x.dtype))
+            z2 = jax.tree.map(clean, z2)
+
+        stale = {"theta0": stale_theta0, "z1": z1, "z2": z2}
+        return state._replace(theta2=theta2, stale=stale, batch=batch, key=key)
 
 
 def global_aggregation(state: HSGDState, fed: FederationConfig, group_weights) -> HSGDState:
@@ -451,15 +475,16 @@ def global_aggregation(state: HSGDState, fed: FederationConfig, group_weights) -
     """
     M = fed.num_groups
     A = jax.tree_util.tree_leaves(state.theta2)[0].shape[1]
-    theta2_group = F.local_aggregate(state.theta2)
-    g0 = F.global_aggregate(state.theta0, group_weights)
-    g1 = F.global_aggregate(state.theta1, group_weights)
-    g2 = F.global_aggregate(theta2_group, group_weights)
-    return state._replace(
-        theta0=F.broadcast_to_groups(g0, M),
-        theta1=F.broadcast_to_groups(g1, M),
-        theta2=F.broadcast_to_devices(F.broadcast_to_groups(g2, M), A),
-    )
+    with jax.named_scope("global_aggregation"):
+        theta2_group = F.local_aggregate(state.theta2)
+        g0 = F.global_aggregate(state.theta0, group_weights)
+        g1 = F.global_aggregate(state.theta1, group_weights)
+        g2 = F.global_aggregate(theta2_group, group_weights)
+        return state._replace(
+            theta0=F.broadcast_to_groups(g0, M),
+            theta1=F.broadcast_to_groups(g1, M),
+            theta2=F.broadcast_to_devices(F.broadcast_to_groups(g2, M), A),
+        )
 
 
 def global_model(state: HSGDState, group_weights) -> Dict[str, Any]:

@@ -1,4 +1,7 @@
 """Algorithm-level HSGD tests: staleness semantics, intervals, compression."""
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -279,3 +282,63 @@ def test_q_interval_counts():
     w = make_group_weights(data)
     state, losses = runner.run(state, data, w, rounds=4)
     assert len(losses) == 4 * 6
+
+
+# ---------------------------------------------------------------------------
+# Named scopes of Algorithm 1's phases: metadata only
+# ---------------------------------------------------------------------------
+
+_METADATA = re.compile(r', metadata=\{(?:[^}"]|"(?:[^"\\]|\\.)*")*\}')
+
+
+def _program_text(hlo: str) -> str:
+    """Optimized HLO text without what names and places the ops: each
+    instruction's metadata and the module's file/stack-frame tables."""
+    out, skip = [], False
+    for line in hlo.splitlines():
+        if line == "FileNames":
+            skip = True
+        if skip and line.startswith(("%", "ENTRY")):
+            skip = False
+        if not skip:
+            out.append(_METADATA.sub("", line))
+    return "\n".join(out)
+
+
+def _compiled_round(executor: str) -> str:
+    """The optimized HLO of one of the runner's executors at a tiny size."""
+    model, fed, data = _mini()
+    state = init_state(jax.random.PRNGKey(0), model, fed, data)
+    w, lr = make_group_weights(data), jnp.float32(0.05)
+    runner = HSGDRunner(model, fed, TrainConfig(compression_k=0.25, quantization_bits=128))
+    M, A = fed.num_groups, fed.sampled_devices
+    idx = F.sample_participants(jax.random.PRNGKey(1), fed)
+    pmask = jnp.ones((M, A), jnp.float32)
+    if executor == "round":
+        low = runner.round_fn(4, 2, collect_stats=False).lower(state, data, w, lr)
+    elif executor == "adaptive":
+        low = runner.round_fn(4, 2, collect_stats=True).lower(state, data, w, lr)
+    elif executor == "cohort":
+        low = runner.cohort_round_fn(4, 2, A, collect_stats=False).lower(
+            state, data, w, lr, idx, pmask)
+    elif executor == "robust":
+        low = runner.fault_round_fn(4, 2, A).lower(
+            state, data, w, lr, idx, pmask, jnp.zeros((M, A)), jnp.zeros((M,)))
+    else:  # private
+        low = runner.round_fn(4, 2, collect_stats=False, dp=True).lower(
+            state, data, w, lr, jnp.float32(1.0), jnp.float32(0.5))
+    return low.compile().as_text()
+
+
+@pytest.mark.parametrize("executor", ["round", "adaptive", "cohort", "robust", "private"])
+def test_phase_scopes_change_metadata_only(executor, monkeypatch):
+    """Every executor carries the phase scopes, and the program with them is
+    the program without them once the metadata is taken out."""
+    scoped = _compiled_round(executor)
+    for name in ("local_step/hospital", "local_step/device", "exchange/compress",
+                 "exchange/local_aggregation"):
+        assert f"/{name}/" in scoped, name
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = _compiled_round(executor)
+    assert "local_step/" not in plain
+    assert _program_text(scoped) == _program_text(plain)
