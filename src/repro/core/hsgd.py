@@ -41,6 +41,7 @@ from repro.optim import halving_schedule
 PHASE_SCOPES = (
     "local_step/hospital",         # eqs. (5)-(6): the θ0/θ1 step
     "local_step/device",           # eq. (7): the per-device θ2 step
+    "local_step/device/conv",      # its conv stack, devices on the lanes (cnn)
     "exchange",                    # lines 10-21: key split, fault and screen legs
     "exchange/local_aggregation",  # eq. (1) and the line-15 broadcast
     "exchange/sample",             # A_m/ξ_m draw and batch gather (line 13)
@@ -172,11 +173,12 @@ def _local_grads(model: HybridModel, state: HSGDState):
     def d_loss(t2_n, x2_n, y_n, t0_m, z1_n):
         return _device_loss(model, t2_n, x2_n, y_n, t0_m, z1_n)
 
-    per_device = jax.vmap(  # over devices within a group
-        jax.grad(d_loss), in_axes=(0, 0, 0, None, 0)
-    )
+    # the model's own batched form where it has one, else grad of the batch-1
+    # loss vmapped over devices within a group, then over groups
+    device_grads = model.device_grads or jax.vmap(
+        jax.vmap(jax.grad(d_loss), in_axes=(0, 0, 0, None, 0)))
     with jax.named_scope("local_step/device"):
-        g2 = jax.vmap(per_device)(  # over groups
+        g2 = device_grads(
             state.theta2, state.batch["x2"], state.batch["y"], state.stale["theta0"],
             state.stale["z1"]
         )

@@ -84,6 +84,49 @@ def tower_forward(params, x_flat, in_rows: int, width: int = 28, n_conv: int = 2
     return L.dense(params["proj"], x)
 
 
+def conv2d_lanes(params, x):
+    """SAME conv with a filter per sample and the sample axis last (on the
+    lanes): x [H, W, Cin, N], params w [k, k, Cin, Cout, N], b [Cout, N] ->
+    [H, W, Cout, N].
+
+    With every sample holding its own filter there is no shared GEMM to feed
+    the MXU, and under vmap over batch-1 samples ``conv2d``'s im2col, relu
+    and pool run with 1- to 144-wide channel dims minor. Here each output is
+    k·k·Cin multiply-adds over full-lane rows, and the shifted taps slice
+    major dims only."""
+    w, b = params["w"], params["b"]
+    k, _, c_in, c_out, n = w.shape
+    H, W = x.shape[:2]
+    p = k // 2
+    xp = jnp.pad(x, ((p, p), (p, p), (0, 0), (0, 0)))
+    taps = jnp.stack([xp[i:i + H, j:j + W] for i in range(k) for j in range(k)], axis=2)
+    w = w.reshape(k * k, c_in, c_out, n)
+    return jnp.sum(taps[:, :, :, :, None] * w, axis=(2, 3)) + b
+
+
+def max_pool_2x2_lanes(x):
+    """``max_pool_2x2`` over [H, W, C, N] (the same windows and crop)."""
+    h, w = x.shape[:2]
+    return x[: h // 2 * 2, : w // 2 * 2].reshape(
+        h // 2, 2, w // 2, 2, *x.shape[2:]).max(axis=(1, 3))
+
+
+def tower_features_lanes(conv_params, x_flat, in_rows: int, width: int = 28):
+    """``tower_forward``'s conv stack for a batch of towers that each see one
+    sample, laid out with the tower axis on the lanes.
+
+    conv_params: {conv<i>: {w [N, k, k, Cin, Cout], b [N, Cout]}} (one tower
+    per row), x_flat [N, in_rows*width] -> the flattened pooled activation
+    [N, rows*cols*C] in ``tower_forward``'s (row, col, channel) order."""
+    n = x_flat.shape[0]
+    lanes = lambda a: jnp.moveaxis(a, 0, -1)
+    x = lanes(x_flat.reshape(n, in_rows, width))[:, :, None]  # [H, W, 1, N]
+    for i in range(len(conv_params)):
+        x = jax.nn.relu(conv2d_lanes(jax.tree.map(lanes, conv_params[f"conv{i}"]), x))
+        x = max_pool_2x2_lanes(x)
+    return jnp.moveaxis(x, -1, 0).reshape(n, -1)
+
+
 def combined_specs(embed_dim: int, n_classes: int, hidden: int = 128):
     return {
         "fc1": L.dense_specs(2 * embed_dim, hidden, (None, None)),

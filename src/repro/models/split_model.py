@@ -41,6 +41,10 @@ class HybridModel:
     h2: Callable  # (θ2, x2) -> ζ2
     loss: Callable  # (θ0, ζ1, ζ2, y) -> scalar
     predict: Callable  # (θ0, ζ1, ζ2) -> outputs
+    # (θ2 [M, A, ...], x2 [M, A, ...], y [M, A], stale θ0 [M, ...], stale ζ1
+    # [M, A, ...]) -> eq. (7)'s per-device g2 [M, A, ...], for a model whose
+    # device tower has a faster batched form than vmap over batch-1 towers
+    device_grads: Optional[Callable] = None
 
     def specs(self) -> Dict[str, Any]:
         return {"theta0": self.specs0, "theta1": self.specs1, "theta2": self.specs2}
@@ -86,6 +90,28 @@ def cnn_hybrid(
     def loss(t0, z1, z2, y):
         return C.classification_loss(predict(t0, z1, z2), y)
 
+    def device_loss(proj, feats_n, y_n, t0_m, z1_n):
+        return loss(t0_m, z1_n[None], L.dense(proj, feats_n[None]), y_n[None])
+
+    def device_grads(t2, x2, y, t0, z1):
+        """Each device's θ2 owns its filters, so the conv stack runs for a
+        group's A devices at once with the device axis on the lanes (vmapped
+        over the M groups); the proj leaf and the combined head stay
+        per-device under vmap as in ``_device_loss``. θ2_n reaches only
+        device n's loss, so the gradient of their sum is every device's own
+        g2."""
+        def features(convs, x2_m):  # one group's A devices on the lanes
+            return C.tower_features_lanes(convs, x2_m, d_rows, width)
+
+        def total(t2):
+            convs = {k: v for k, v in t2.items() if k != "proj"}
+            with jax.named_scope("conv"):
+                feats = jax.vmap(features)(convs, x2)
+            per_device = jax.vmap(device_loss, in_axes=(0, 0, 0, None, 0))
+            return jnp.sum(jax.vmap(per_device)(t2["proj"], feats, y, t0, z1))
+
+        return jax.grad(total)(t2)
+
     return HybridModel(
         name="paper_cnn",
         specs0=C.combined_specs(embed_dim, n_classes),
@@ -95,6 +121,7 @@ def cnn_hybrid(
         h2=h2,
         loss=loss,
         predict=predict,
+        device_grads=device_grads,
     )
 
 

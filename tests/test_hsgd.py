@@ -1,5 +1,6 @@
 """Algorithm-level HSGD tests: staleness semantics, intervals, compression."""
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -342,3 +343,91 @@ def test_phase_scopes_change_metadata_only(executor, monkeypatch):
     plain = _compiled_round(executor)
     assert "local_step/" not in plain
     assert _program_text(scoped) == _program_text(plain)
+
+
+# ---------------------------------------------------------------------------
+# The model's batched device-tower gradients against the generic path
+# ---------------------------------------------------------------------------
+
+
+def _step(name):
+    """One of the three step functions that share ``_local_grads``, as
+    (model, state) -> its outputs."""
+    from repro.core.hsgd import local_sgd_step_guarded, local_sgd_step_stats
+
+    def guarded(model, state):
+        M, A = state.batch["y"].shape
+        fault = jnp.zeros((M, A)).at[1, A - 1].set(1e4)  # one device's g2 blown up
+        return local_sgd_step_guarded(model, state, 0.05, jnp.ones((M, A)),
+                                      grad_fault=fault, screen=True)
+
+    def stats(model, state):
+        return local_sgd_step_stats(model, state, 0.05, jnp.ones((state.batch["y"].shape[0],)))
+
+    def plain(model, state):
+        return local_sgd_step(model, state, 0.05)
+
+    return {"plain": plain, "stats": stats, "guarded": guarded}[name]
+
+
+@pytest.mark.parametrize("step", ["plain", "stats", "guarded"])
+@pytest.mark.parametrize("h_rows", [11, 12])  # 17 and 16 device rows: odd and even pool crops
+@pytest.mark.parametrize("A", [3, 4, 9])  # M·A = 6, 8, 18 devices on the lanes
+def test_lane_dense_device_grads_match_the_generic_path(A, h_rows, step, monkeypatch):
+    """cnn_hybrid's lane-dense ``device_grads`` gives every step function the
+    g2 of vmap(vmap(grad)) over batch-1 towers, to f32 rounding."""
+    spec = dataclasses.replace(ORGANAMNIST, hospital_size=h_rows)
+    fed = FederationConfig(num_groups=2, devices_per_group=2 * A, alpha=0.5,
+                           local_interval=2, global_interval=4)
+    X, y = make_dataset(spec, 4 * A, seed=A)
+    data = {k: jnp.asarray(v) for k, v in hybrid_partition(spec, X, y, fed, seed=0).stacked().items()}
+    model = cnn_hybrid(h_rows=h_rows)
+    assert model.device_grads is not None
+    generic = dataclasses.replace(model, device_grads=None)
+    state = init_state(jax.random.PRNGKey(A), model, fed, data)
+    # one step past the exchange, so the devices differ from their group
+    state = jax.jit(lambda s: local_sgd_step(generic, exchange(generic, s, data, fed), 0.05)[0])(state)
+    # each step function then returns as its new θ2 the g2 it would apply
+    # (after the guarded step's fault injection and screening)
+    monkeypatch.setattr("repro.core.hsgd._apply_sgd",
+                        lambda state, lr, g0, g1, g2: state._replace(theta2=g2))
+    fn = _step(step)
+    (got, *rest) = jax.jit(lambda s: fn(model, s))(state)
+    (want, *rest_want) = jax.jit(lambda s: fn(generic, s))(state)
+    got, want = got.theta2, want.theta2
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == w.shape and np.isfinite(g).all()
+        # rtol for the entries, plus f32 rounding of the leaf's largest one
+        # (summation order differs) for the entries near zero
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5 * np.abs(w).max())
+    if step == "guarded":  # the same device flagged by the screen
+        np.testing.assert_array_equal(np.asarray(rest[1]), np.asarray(rest_want[1]))
+        assert float(np.asarray(rest[1])[1, A - 1]) == 0.0  # dev_ok
+
+
+def test_models_without_device_grads_keep_the_generic_path():
+    """lstm_hybrid supplies no ``device_grads``: its g2 is vmap(vmap(grad))
+    of the batch-1 device loss, bit for bit."""
+    from repro.core.hsgd import _device_loss, _local_grads
+    from repro.data.synthetic import MIMIC3
+    from repro.models.split_model import lstm_hybrid
+
+    fed = FederationConfig(num_groups=2, devices_per_group=6, alpha=0.5,
+                           local_interval=2, global_interval=4)
+    X, y = make_dataset(MIMIC3, 12, seed=0)
+    data = {k: jnp.asarray(v) for k, v in hybrid_partition(MIMIC3, X, y, fed, seed=0).stacked().items()}
+    model = lstm_hybrid(n_features=76, hospital_features=36, n_classes=MIMIC3.n_classes)
+    assert model.device_grads is None
+    state = jax.jit(lambda s: exchange(model, s, data, fed))(
+        init_state(jax.random.PRNGKey(0), model, fed, data))
+    got = jax.jit(lambda s: _local_grads(model, s)[3])(state)
+
+    def d_loss(t2, x2, y, t0, z1):
+        return _device_loss(model, t2, x2, y, t0, z1)
+
+    want = jax.jit(lambda s: jax.vmap(jax.vmap(jax.grad(d_loss), in_axes=(0, 0, 0, None, 0)))(
+        s.theta2, s.batch["x2"], s.batch["y"], s.stale["theta0"], s.stale["z1"]))(state)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
