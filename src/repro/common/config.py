@@ -33,7 +33,9 @@ class ModelConfig:
     sliding_window: int = 0  # 0 -> full attention
     local_global_ratio: int = 0  # gemma3: 5 local per 1 global
     qk_norm: bool = False
+    qkv_bias: bool = False  # stablelm-2: biases on the q/k/v projections
     rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0  # share of head_dim that RoPE rotates
     mrope_sections: Tuple[int, ...] = ()  # qwen2-vl M-RoPE (t, h, w) splits
     # --- MLA (deepseek) ---
     q_lora_rank: int = 0
@@ -62,6 +64,7 @@ class ModelConfig:
     frontend: str = ""  # "audio" | "vision" stub marker
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     tie_embeddings: bool = True
+    embed_scale: bool = True  # token embeddings times sqrt(d_model)
     max_seq_len: int = 131072
     dtype: str = "bfloat16"
     # provenance
@@ -83,6 +86,8 @@ class ModelConfig:
             kv = 2 * d * self.num_kv_heads * hd
             o = self.num_heads * hd * d
             attn = q + kv + o
+            if self.qkv_bias:
+                attn += (self.num_heads + 2 * self.num_kv_heads) * hd
         elif self.attention == "mla":
             attn = d * self.q_lora_rank + self.q_lora_rank * self.num_heads * (hd + self.qk_rope_head_dim)
             attn += d * (self.kv_lora_rank + self.qk_rope_head_dim)

@@ -1,5 +1,11 @@
 """stablelm-1.6b [dense] — 24L d=2048 32H (GQA kv=32) ff=5632 V=100352.
-[hf:stabilityai/stablelm-2-1_6b]"""
+[hf:stabilityai/stablelm-2-1_6b]
+
+``stablelm-1.6b`` is the repo's generic dense decoder at these widths: RoPE
+over the whole head, no q/k/v biases, embeddings scaled by sqrt(d_model).
+``stablelm-2-1.6b`` is the published architecture: RoPE over the first
+quarter of each head (``partial_rotary_factor`` 0.25), biases on the q, k and
+v projections (``use_qkv_bias``), embeddings unscaled."""
 from repro.common.config import ModelConfig, register_config
 
 
@@ -17,4 +23,18 @@ def smoke() -> ModelConfig:
                           d_ff=256, vocab_size=512)
 
 
+# what stablelm-2-1_6b's config.json sets apart from ``full()``
+STABLELM2 = dict(name="stablelm-2-1.6b", partial_rotary_factor=0.25, qkv_bias=True,
+                 embed_scale=False, tie_embeddings=False, max_seq_len=4096)
+
+
+def published() -> ModelConfig:
+    return full().replace(**STABLELM2)
+
+
+def published_smoke() -> ModelConfig:
+    return smoke().replace(**STABLELM2)
+
+
 register_config("stablelm-1.6b", full, smoke)
+register_config("stablelm-2-1.6b", published, published_smoke)

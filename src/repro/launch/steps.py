@@ -168,15 +168,17 @@ def make_hybrid(cfg: ModelConfig, n_tower: int = 2, remat: bool = True) -> Hybri
 def hybrid_grads(model: HybridModel, params, stale, batch):
     """The eqs. (5)–(7) gradients for one worker: hospital (θ0, θ1) with fresh
     ζ1/stale ζ2, device θ2 with stale θ0/ζ1. Shared by the plain train step
-    and the probe-collecting stats step."""
+    and the probe-collecting stats step. Each gradient runs under its phase's
+    scope of ``core/hsgd.PHASE_SCOPES``."""
 
     def hosp_loss(t0, t1):
         z1 = model.h1(t1, batch["x1"])
         return model.loss(t0, z1, jax.lax.stop_gradient(stale["z2"]), batch["y"])
 
-    loss, (g0, g1) = jax.value_and_grad(hosp_loss, argnums=(0, 1))(
-        params["theta0"], params["theta1"]
-    )
+    with jax.named_scope("local_step/hospital"):
+        loss, (g0, g1) = jax.value_and_grad(hosp_loss, argnums=(0, 1))(
+            params["theta0"], params["theta1"]
+        )
 
     def dev_loss(t2):
         z2 = model.h2(t2, batch["x2"])
@@ -187,12 +189,21 @@ def hybrid_grads(model: HybridModel, params, stale, batch):
             batch["y"],
         )
 
-    g2 = jax.grad(dev_loss)(params["theta2"])
+    with jax.named_scope("local_step/device"):
+        g2 = jax.grad(dev_loss)(params["theta2"])
     return loss, {"theta0": g0, "theta1": g1, "theta2": g2}
 
 
 def _apply_update(params, grads, lr):
-    return jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype), params, grads)
+    """SGD on {θ0, θ1, θ2}: the hospital update (eqs. 5–6) and the device
+    update (eq. 7), each under its phase's scope."""
+    upd = lambda p, g: p - lr * g.astype(p.dtype)
+    with jax.named_scope("local_step/hospital"):
+        theta0 = jax.tree.map(upd, params["theta0"], grads["theta0"])
+        theta1 = jax.tree.map(upd, params["theta1"], grads["theta1"])
+    with jax.named_scope("local_step/device"):
+        theta2 = jax.tree.map(upd, params["theta2"], grads["theta2"])
+    return {"theta0": theta0, "theta1": theta1, "theta2": theta2}
 
 
 def make_hsgd_train_step(model: HybridModel, lr: float = 1e-3) -> Callable:
@@ -263,17 +274,20 @@ def make_exchange_step(model: HybridModel, compression_k: float = 0.0, quant: in
     """
 
     def exchange(params, batch, dp_clip=None, dp_sigma=None, dp_key=None):
-        z1 = model.h1(params["theta1"], batch["x1"])
-        z2 = model.h2(params["theta2"], batch["x2"])
-        msg = {"theta0": params["theta0"], "z1": z1, "z2": z2}
-        if compression_k or quant or dp:
-            from repro.kernels.compress import compress_pytree
+        with jax.named_scope("exchange"):
+            with jax.named_scope("intermediate"):
+                z1 = model.h1(params["theta1"], batch["x1"])
+                z2 = model.h2(params["theta2"], batch["x2"])
+            msg = {"theta0": params["theta0"], "z1": z1, "z2": z2}
+            if compression_k or quant or dp:
+                from repro.kernels.compress import compress_pytree
 
-            msg = compress_pytree(msg, compression_k or 1.0, quant,
-                                  dp_clip=dp_clip if dp else None,
-                                  dp_sigma=dp_sigma if dp else None,
-                                  dp_key=dp_key if dp else None)
-        return msg
+                with jax.named_scope("compress"):
+                    msg = compress_pytree(msg, compression_k or 1.0, quant,
+                                          dp_clip=dp_clip if dp else None,
+                                          dp_sigma=dp_sigma if dp else None,
+                                          dp_key=dp_key if dp else None)
+            return msg
 
     return exchange
 
@@ -289,21 +303,22 @@ def make_global_agg() -> Callable:
     """
 
     def agg(params, pod_weights=None):
-        if pod_weights is None:
+        with jax.named_scope("global_aggregation"):
+            if pod_weights is None:
+                def m(x):
+                    g = jnp.mean(x.astype(jnp.float32), axis=0, keepdims=True).astype(x.dtype)
+                    return jnp.broadcast_to(g, x.shape)
+
+                return jax.tree.map(m, params)
+            w = pod_weights.astype(jnp.float32)
+            w = w / jnp.sum(w)
+
             def m(x):
-                g = jnp.mean(x.astype(jnp.float32), axis=0, keepdims=True).astype(x.dtype)
+                wb = w.reshape((-1,) + (1,) * (x.ndim - 1))
+                g = jnp.sum(x.astype(jnp.float32) * wb, axis=0, keepdims=True).astype(x.dtype)
                 return jnp.broadcast_to(g, x.shape)
 
             return jax.tree.map(m, params)
-        w = pod_weights.astype(jnp.float32)
-        w = w / jnp.sum(w)
-
-        def m(x):
-            wb = w.reshape((-1,) + (1,) * (x.ndim - 1))
-            g = jnp.sum(x.astype(jnp.float32) * wb, axis=0, keepdims=True).astype(x.dtype)
-            return jnp.broadcast_to(g, x.shape)
-
-        return jax.tree.map(m, params)
 
     return agg
 
@@ -488,6 +503,12 @@ class LLMRoundRunner:
     batches carry [Λ, G, ...] — one fresh token-stream batch per exchange
     interval per pod, so every exchange resamples instead of training on a
     frozen batch.
+
+    The round names Algorithm 1's phases with the scopes of
+    ``core/hsgd.PHASE_SCOPES`` (``local_step/hospital``,
+    ``local_step/device``, ``exchange`` with ``intermediate`` and
+    ``compress``, ``global_aggregation``), so a device trace splits its time
+    by phase as it does the CNN round's. Scopes change metadata only.
     """
 
     model: HybridModel

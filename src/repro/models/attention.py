@@ -34,6 +34,10 @@ def gqa_specs(cfg: ModelConfig) -> Dict[str, L.Spec]:
         "wv": L.Spec((d, cfg.num_kv_heads, hd), ("embed", "kv_heads", "head_dim")),
         "wo": L.Spec((cfg.num_heads, hd, d), ("heads", "head_dim", "embed")),
     }
+    if cfg.qkv_bias:
+        s["q_bias"] = L.Spec((cfg.num_heads, hd), ("heads", "head_dim"), "zeros")
+        s["k_bias"] = L.Spec((cfg.num_kv_heads, hd), ("kv_heads", "head_dim"), "zeros")
+        s["v_bias"] = L.Spec((cfg.num_kv_heads, hd), ("kv_heads", "head_dim"), "zeros")
     if cfg.qk_norm:
         s["q_norm"] = L.Spec((hd,), ("head_dim",), "ones")
         s["k_norm"] = L.Spec((hd,), ("head_dim",), "ones")
@@ -240,6 +244,10 @@ def gqa_forward(
     q = jnp.einsum("bsd,dhk->bshk", x, wq.astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, wk.astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, wv.astype(x.dtype))
+    if cfg.qkv_bias:
+        q = q + params["q_bias"].astype(x.dtype)
+        k = k + params["k_bias"].astype(x.dtype)
+        v = v + params["v_bias"].astype(x.dtype)
     if cfg.qk_norm:
         q = _head_rms(q, params["q_norm"])
         k = _head_rms(k, params["k_norm"])
@@ -248,8 +256,9 @@ def gqa_forward(
         q = L.apply_mrope(q, p3, cfg.mrope_sections, cfg.rope_theta)
         k = L.apply_mrope(k, p3, cfg.mrope_sections, cfg.rope_theta)
     else:
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
+        rot = int(hd * cfg.partial_rotary_factor)
+        q = L.apply_partial_rope(q, positions, cfg.rope_theta, rot)
+        k = L.apply_partial_rope(k, positions, cfg.rope_theta, rot)
     q = constrain(q, ("batch", "seq", "heads", None))
     scale = hd ** -0.5
 

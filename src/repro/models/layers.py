@@ -177,6 +177,14 @@ def apply_rope(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
+def apply_partial_rope(x, positions, theta: float, rot: int):
+    """RoPE over the first ``rot`` channels of each head (frequencies from
+    ``rot``, not the head width); the rest pass through unrotated."""
+    if rot == x.shape[-1]:
+        return apply_rope(x, positions, theta)
+    return jnp.concatenate([apply_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1)
+
+
 def apply_mrope(x, positions_3d, sections: Tuple[int, ...], theta: float = 10000.0):
     """Qwen2-VL multimodal RoPE.
 

@@ -188,7 +188,8 @@ def _tower_stack_specs(cfg: ModelConfig, n_tower: int, with_embed: bool):
 def _tower_forward(tcfg: ModelConfig, params, x_or_tokens, remat=True):
     if "embed" in params:
         x = L.embed(params["embed"], x_or_tokens)
-        x = x * jnp.asarray(jnp.sqrt(jnp.float32(tcfg.d_model)), x.dtype)
+        if tcfg.embed_scale:
+            x = x * jnp.asarray(jnp.sqrt(jnp.float32(tcfg.d_model)), x.dtype)
     else:
         x = x_or_tokens
     x, _ = T.backbone_forward(tcfg, {"layers": params["layers"]}, x, remat=remat)

@@ -232,6 +232,15 @@ def model_specs(cfg: ModelConfig) -> Dict:
     return s
 
 
+def embed_tokens(cfg, params, tokens):
+    """Token embeddings, scaled by sqrt(d_model) unless ``cfg.embed_scale``
+    is off (stablelm-2 feeds them to the first layer as they are)."""
+    x = L.embed(params["embed"], tokens)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+    return x
+
+
 def _vlm_inputs(cfg, params, tokens, vision_embeds):
     """qwen2-vl: prepend stubbed patch embeddings to the token embeddings."""
     x_txt = L.embed(params["embed"], tokens) * jnp.sqrt(jnp.float32(cfg.d_model)).astype(jnp.bfloat16)
@@ -267,8 +276,7 @@ def forward(
     elif cfg.family == "audio":
         x = L.embed(params["embed"], tokens)
     else:
-        x = L.embed(params["embed"], tokens)
-        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+        x = embed_tokens(cfg, params, tokens)
     x = constrain(x, ("batch", "seq", "embed"))
     if cfg.family == "audio":
         x = audio_forward(params, x, extra_embeds, None, cfg, remat)
@@ -598,8 +606,7 @@ def decode_step(cfg: ModelConfig, params, tokens, caches, index, force_window=Fa
     Returns (logits [B, S, V], new_caches).
     """
     B, S = tokens.shape
-    x = L.embed(params["embed"], tokens)
-    x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+    x = embed_tokens(cfg, params, tokens)
     if jnp.ndim(index) == 1:
         if S != 1 and cfg.family == "hybrid":
             # ring-buffer attention caches wrap write positions with a
@@ -672,8 +679,7 @@ def draft_decode_step(cfg: ModelConfig, params, tokens, caches, index, draft_lay
     if not (0 < draft_layers < cfg.num_layers):
         raise ValueError(f"draft_layers must be in (0, {cfg.num_layers}), got {draft_layers}")
     B, S = tokens.shape
-    x = L.embed(params["embed"], tokens)
-    x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+    x = embed_tokens(cfg, params, tokens)
     positions = jnp.asarray(index, jnp.int32)[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     windows = layer_windows(cfg, cfg.num_layers)
     kv = caches["kv"]
