@@ -17,9 +17,12 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.common.config import FederationConfig
 from repro.common.sharding import constrain
+
+LANES = 128  # the TPU vector unit's minor tile width
 
 
 def _group_axes(x):
@@ -29,6 +32,21 @@ def _group_axes(x):
 def _constrain_grouped(tree):
     """Tag the leading group axis of every [M, ...] leaf."""
     return jax.tree.map(lambda x: constrain(x, _group_axes(x)), tree)
+
+
+def lane_dense(x):
+    """``x`` [M, A, ..., R, C] held with its last two dims swapped in the
+    layout where the per-device leaf is C < 128 wide and R >= 128 tall.
+
+    A minor dim narrower than the lanes pads every tile, so each pass over
+    the leaf moves the padding too (the cnn tower's proj [896, 64]: twice its
+    bytes). Only the physical layout changes: the shape, values and arithmetic
+    stay; XLA carries the layout on to the scan carries and aggregations.
+    """
+    if x.ndim < 4 or x.shape[-1] >= LANES or x.shape[-2] < LANES:
+        return x
+    order = (*range(x.ndim - 2), x.ndim - 1, x.ndim - 2)
+    return with_layout_constraint(x, Layout(major_to_minor=order))
 
 
 def local_aggregate(theta2_active, mask=None):
@@ -255,7 +273,8 @@ def robust_local_aggregate(theta2_active, pmask, trust, method: str = "median",
 
     def robust_path(_):
         def sel(x_full, x_plain):
-            rob = _robust_center(x_full, w, method, trim_frac)
+            # a conditional's branch takes no layout from its operand: pin it
+            rob = _robust_center(lane_dense(x_full), w, method, trim_frac)
             keep = use_robust.reshape((-1,) + (1,) * (x_plain.ndim - 1))
             return jnp.where(keep, rob, x_plain)
 

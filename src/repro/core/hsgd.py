@@ -191,7 +191,7 @@ def _apply_sgd(state: HSGDState, lr, g0, g1, g2) -> HSGDState:
         theta0 = jax.tree.map(upd, state.theta0, g0)
         theta1 = jax.tree.map(upd, state.theta1, g1)
     with jax.named_scope("local_step/device"):
-        theta2 = jax.tree.map(upd, state.theta2, g2)
+        theta2 = jax.tree.map(lambda p, g: F.lane_dense(upd(p, g)), state.theta2, g2)
     return state._replace(theta0=theta0, theta1=theta1, theta2=theta2,
                           step=state.step + 1)
 
@@ -262,7 +262,8 @@ def _inject_grads(g2, grad_fault):
         def leaf(g):
             f = grad_fault.reshape(
                 grad_fault.shape + (1,) * (g.ndim - 2)).astype(g.dtype)
-            return jnp.where(f != 0, g + f, g)
+            # pinned as in _apply_sgd: a conditional's branch takes no layout
+            return F.lane_dense(jnp.where(f != 0, g + f, g))
 
         return jax.tree.map(leaf, g2)
 

@@ -8,6 +8,7 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +104,48 @@ def test_compress_under_a_mesh_compiles_for_v5e(topo, monkeypatch):
     with mesh_context(mesh):
         compiled = jax.jit(lambda x: C.compress_rows(x, 32, 128)).lower(x).compile()
     assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("executor", ["round", "cohort", "robust"])
+def test_theta2_proj_held_lane_dense_for_v5e(one_chip, executor, monkeypatch):
+    """The compiled C-HSGD round (P 4, Q 2, k 0.25, b 128) of the paper's CNN
+    at 2 groups x 64 devices, α 0.25 (A = 16): no buffer of the per-device θ2
+    proj leaf f32[2, 16, 896, 64] puts its 64-wide dim on the lanes, where
+    every tile would be half padding, and no θ2-sized relayout copy runs.
+    ``robust`` is the fault executor, whose conditionals (fault injection,
+    robust eq. (1)) take no layout from their operands."""
+    from repro.common.config import FederationConfig, TrainConfig
+    from repro.core.hsgd import HSGDRunner, init_state, make_group_weights
+    from repro.data.partition import hybrid_partition
+    from repro.data.synthetic import ORGANAMNIST, make_dataset
+    from repro.kernels import compress as C
+    from repro.models.split_model import cnn_hybrid
+
+    monkeypatch.setattr(C, "default_interpret", lambda: False)
+    M, K = 2, 64
+    fed = FederationConfig(num_groups=M, devices_per_group=K, alpha=0.25,
+                           local_interval=2, global_interval=4)
+    X, y = make_dataset(ORGANAMNIST, M * K, seed=0)
+    data = {k: jnp.asarray(v) for k, v in
+            hybrid_partition(ORGANAMNIST, X, y, fed, seed=0).stacked().items()}
+    model = cnn_hybrid()
+    state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0), model, fed, data))
+    on_chip = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    args = on_chip((state, data, make_group_weights(data), jnp.float32(0.01)))
+    runner = HSGDRunner(model, fed, TrainConfig(compression_k=0.25, quantization_bits=128))
+    A = fed.sampled_devices
+    idx = on_chip(jax.ShapeDtypeStruct((M, A), jnp.int32))
+    mask = on_chip(jax.ShapeDtypeStruct((M, A), jnp.float32))
+    if executor == "round":
+        lowered = runner.round_fn(4, 2, collect_stats=False).lower(*args)
+    elif executor == "cohort":
+        lowered = runner.cohort_round_fn(4, 2, A, collect_stats=False).lower(*args, idx, mask)
+    else:
+        msg_fault = on_chip(jax.ShapeDtypeStruct((M,), jnp.float32))
+        lowered = runner.fault_round_fn(4, 2, A).lower(*args, idx, mask, mask, msg_fault)
+    text = lowered.compile().as_text()
+    proj = rf"f32\[{M},{A},896,64\]\{{(\d+),"
+    minor = [int(d) for d in re.findall(proj, text)]
+    assert minor, "no θ2 proj buffer in the compiled round"
+    assert minor.count(3) == 0, f"{minor.count(3)} of {len(minor)} θ2 proj buffers padded"
+    assert not re.search(rf"= f32\[{M},{A},896,64\]\{{[^}}]*\}} copy\(", text)

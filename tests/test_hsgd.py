@@ -407,6 +407,70 @@ def test_lane_dense_device_grads_match_the_generic_path(A, h_rows, step, monkeyp
         assert float(np.asarray(rest[1])[1, A - 1]) == 0.0  # dev_ok
 
 
+# ---------------------------------------------------------------------------
+# θ2 leaves held lane-dense: the layout only
+# ---------------------------------------------------------------------------
+
+
+def _round_of(model, fed, data, monkeypatch, bypass: bool, executor: str = "round"):
+    """(lowered text, compiled text, state, losses) of one C-HSGD round with
+    ``lane_dense`` in place or bypassed. ``robust``: the fault executor with
+    one device's gradient blown up, so the screen flags it and eq. (1) takes
+    the coordinate-wise median."""
+    with monkeypatch.context() as m:
+        if bypass:
+            m.setattr(F, "lane_dense", lambda x: x)
+        runner = HSGDRunner(model, fed, TrainConfig(compression_k=0.25, quantization_bits=128))
+        state = init_state(jax.random.PRNGKey(3), model, fed, data)
+        args = (state, data, make_group_weights(data), jnp.float32(0.05))
+        if executor == "round":
+            fn = runner.round_fn(4, 2, collect_stats=False)
+        else:
+            M, A = fed.num_groups, fed.sampled_devices
+            fn = runner.fault_round_fn(4, 2, A)
+            fault = jnp.zeros((M, A)).at[1, A - 1].set(1e4)
+            args += (F.sample_participants(jax.random.PRNGKey(1), fed),
+                     jnp.ones((M, A)), fault, jnp.zeros((M,)))
+        lowered = fn.lower(*args)
+        state, losses, *flagged = fn(*args)
+    assert executor == "round" or float(flagged[0]) > 0  # the robust branch ran
+    return lowered.as_text(), lowered.compile().as_text(), state, losses
+
+
+@pytest.mark.parametrize("executor", ["round", "robust"])
+def test_lane_dense_theta2_keeps_the_round(executor, monkeypatch):
+    """cnn_hybrid's θ2 proj [896, 64] is pinned lane-dense, and the round
+    gives the same losses and θ leaves as the round without the pin."""
+    model, fed, data = _mini()
+    fed = dataclasses.replace(fed, robust_agg="median")
+    low, _, got, got_losses = _round_of(model, fed, data, monkeypatch, False, executor)
+    low_plain, _, want, want_losses = _round_of(model, fed, data, monkeypatch, True, executor)
+    assert "@LayoutConstraint" in low and "@LayoutConstraint" not in low_plain
+    np.testing.assert_array_equal(np.asarray(got_losses), np.asarray(want_losses))
+    for part in ("theta0", "theta1", "theta2"):
+        for g, w in zip(jax.tree_util.tree_leaves(getattr(got, part)),
+                        jax.tree_util.tree_leaves(getattr(want, part))):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_lane_dense_rule_leaves_lstm_alone(monkeypatch):
+    """lstm_hybrid's θ2 leaves (wx [40, 256], wh [64, 256], proj [64, 64],
+    b [256]) are all lane-wide or short: its compiled round is the same
+    program with the helper and without it."""
+    from repro.data.synthetic import MIMIC3
+    from repro.models.split_model import lstm_hybrid
+
+    fed = FederationConfig(num_groups=2, devices_per_group=6, alpha=0.5,
+                           local_interval=2, global_interval=4)
+    X, y = make_dataset(MIMIC3, 12, seed=0)
+    data = {k: jnp.asarray(v) for k, v in hybrid_partition(MIMIC3, X, y, fed, seed=0).stacked().items()}
+    model = lstm_hybrid(n_features=76, hospital_features=36, n_classes=MIMIC3.n_classes)
+    low, compiled, _, _ = _round_of(model, fed, data, monkeypatch, bypass=False)
+    _, compiled_plain, _, _ = _round_of(model, fed, data, monkeypatch, bypass=True)
+    assert "@LayoutConstraint" not in low
+    assert _program_text(compiled) == _program_text(compiled_plain)
+
+
 def test_models_without_device_grads_keep_the_generic_path():
     """lstm_hybrid supplies no ``device_grads``: its g2 is vmap(vmap(grad))
     of the batch-1 device loss, bit for bit."""
