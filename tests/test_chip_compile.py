@@ -106,23 +106,28 @@ def test_compress_under_a_mesh_compiles_for_v5e(topo, monkeypatch):
     assert KERNEL in compiled.as_text()
 
 
-@pytest.mark.parametrize("executor", ["round", "cohort", "robust"])
-def test_theta2_proj_held_lane_dense_for_v5e(one_chip, executor, monkeypatch):
+@pytest.mark.parametrize("executor", ["round", "cohort", "robust", "mesh"])
+def test_theta2_proj_held_lane_dense_for_v5e(topo, one_chip, executor, monkeypatch):
     """The compiled C-HSGD round (P 4, Q 2, k 0.25, b 128) of the paper's CNN
     at 2 groups x 64 devices, α 0.25 (A = 16): no buffer of the per-device θ2
     proj leaf f32[2, 16, 896, 64] puts its 64-wide dim on the lanes, where
     every tile would be half padding, and no θ2-sized relayout copy runs.
     ``robust`` is the fault executor, whose conditionals (fault injection,
-    robust eq. (1)) take no layout from their operands."""
+    robust eq. (1)) take no layout from their operands. ``mesh`` is the plain
+    round of 4 groups sharded over a 4 x 1 mesh of the four chips: the leaf
+    stays one group a chip, f32[1, 16, 896, 64], and no collective moves it."""
     from repro.common.config import FederationConfig, TrainConfig
-    from repro.core.hsgd import HSGDRunner, init_state, make_group_weights
+    from repro.common.sharding import group_sharding, mesh_context
+    from repro.core.hsgd import (HSGDRunner, init_state, make_group_weights,
+                                 state_shardings)
     from repro.data.partition import hybrid_partition
     from repro.data.synthetic import ORGANAMNIST, make_dataset
     from repro.kernels import compress as C
+    from repro.launch.mesh import make_mesh
     from repro.models.split_model import cnn_hybrid
 
     monkeypatch.setattr(C, "default_interpret", lambda: False)
-    M, K = 2, 64
+    M, K = (4 if executor == "mesh" else 2), 64
     fed = FederationConfig(num_groups=M, devices_per_group=K, alpha=0.25,
                            local_interval=2, global_interval=4)
     X, y = make_dataset(ORGANAMNIST, M * K, seed=0)
@@ -136,16 +141,33 @@ def test_theta2_proj_held_lane_dense_for_v5e(one_chip, executor, monkeypatch):
     A = fed.sampled_devices
     idx = on_chip(jax.ShapeDtypeStruct((M, A), jnp.int32))
     mask = on_chip(jax.ShapeDtypeStruct((M, A), jnp.float32))
+    held = M  # groups of the leaf on one chip
     if executor == "round":
         lowered = runner.round_fn(4, 2, collect_stats=False).lower(*args)
     elif executor == "cohort":
         lowered = runner.cohort_round_fn(4, 2, A, collect_stats=False).lower(*args, idx, mask)
-    else:
+    elif executor == "robust":
         msg_fault = on_chip(jax.ShapeDtypeStruct((M,), jnp.float32))
         lowered = runner.fault_round_fn(4, 2, A).lower(*args, idx, mask, mask, msg_fault)
+    else:
+        mesh = make_mesh((4, 1), ("data", "model"), devices=topo.devices)
+        placed = lambda tree, sh: jax.tree.map(lambda s, h: _sds(s.shape, s.dtype, h), tree, sh)
+        repl = NamedSharding(mesh, P())
+        args = (placed(state, state_shardings(state, mesh)),
+                placed(data, jax.tree.map(lambda x: group_sharding(x.shape, mesh), data)),
+                _sds((M,), jnp.float32, repl), _sds((), jnp.float32, repl))
+        with mesh_context(mesh):
+            lowered = runner.round_fn(4, 2, collect_stats=False).lower(*args)
+        held = 1
     text = lowered.compile().as_text()
-    proj = rf"f32\[{M},{A},896,64\]\{{(\d+),"
+    proj = rf"f32\[{held},{A},896,64\]\{{(\d+),"
     minor = [int(d) for d in re.findall(proj, text)]
     assert minor, "no θ2 proj buffer in the compiled round"
     assert minor.count(3) == 0, f"{minor.count(3)} of {len(minor)} θ2 proj buffers padded"
-    assert not re.search(rf"= f32\[{M},{A},896,64\]\{{[^}}]*\}} copy\(", text)
+    assert not re.search(rf"= f32\[{held},{A},896,64\]\{{[^}}]*\}} copy\(", text)
+    if executor == "mesh":
+        assert f"f32[{M},{A},896,64]" not in text  # never gathered whole
+        moved = [l for l in text.splitlines()
+                 if re.search(r"(all-gather|all-to-all|collective-permute)(-start)?\(", l)
+                 and ",896,64]" in l.split("(")[0]]
+        assert not moved, moved
